@@ -31,6 +31,8 @@ def main() -> None:
                          "Perfetto JSON (sets HELIOS_TRACE before figs "
                          "import; CI uploads it as the trace artifact)")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     if args.smoke:
         # figs reads the env var at import time, so set it before importing
         os.environ["HELIOS_BENCH_SMOKE"] = "1"

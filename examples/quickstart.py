@@ -6,10 +6,12 @@ import tempfile
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.hetero_cache import HeteroCache
 from repro.core.iostack import AsyncIOEngine, FeatureStore
 from repro.core.policy import OnlineDecayPolicy
 
+compile_cache.enable()
 root = tempfile.mkdtemp(prefix="helios_quickstart_")
 
 # 1. a "terabyte-scale" feature table striped over 12 storage shards (SSDs)

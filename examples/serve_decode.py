@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import get_config, list_configs
 from repro.models import encdec, lm, steps
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--tokens", type=int, default=32)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch).reduced()
     key = jax.random.key(0)

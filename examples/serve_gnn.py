@@ -10,6 +10,7 @@ the sync (GIDS-like) and CPU-managed (Ginex-like) baselines.
 import argparse
 import tempfile
 
+from repro import compile_cache
 from repro.core.iostack import FeatureStore
 from repro.gnn.graph import synth_graph
 from repro.serving import GNNInferenceServer, ServerConfig, zipf_workload
@@ -33,6 +34,7 @@ def main():
                          "(admission, batch build, gather, forward, IO "
                          "tickets) to this path; same as HELIOS_TRACE")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.obs import trace as _trace
     if args.trace:

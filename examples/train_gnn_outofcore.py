@@ -9,6 +9,7 @@ serial and CPU-managed baselines.
 import argparse
 import tempfile
 
+from repro import compile_cache
 from repro.core.iostack import FeatureStore
 from repro.gnn.graph import synth_graph
 from repro.gnn.train import OutOfCoreGNNTrainer, TrainerConfig
@@ -29,6 +30,7 @@ def main():
                          "(pipeline phases, IO tickets, cache ops) to this "
                          "path; equivalent to HELIOS_TRACE=OUT.json")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.obs import trace as _trace
     if args.trace:

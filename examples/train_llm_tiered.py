@@ -10,6 +10,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.core.hotness import token_hotness
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--arch", default="qwen2-moe-a2.7b")
     args = ap.parse_args()
+    compile_cache.enable()
 
     root = tempfile.mkdtemp(prefix="helios_llm_")
     cfg = get_config(args.arch).reduced()

@@ -1,8 +1,9 @@
 """GPU-managed heterogeneous cache (paper §3.2, TPU-adapted).
 
-Three tiers: device HBM (hottest rows, ~2 TB/s), host DRAM (second-hottest
-rows + all topology, PCIe-fed), storage shards (everything, via the async
-IO stack).  Placement is owned by a pluggable ``core.policy`` policy —
+Four tiers: device HBM (hottest rows), host DRAM (second-hottest rows +
+all topology, PCIe-fed), storage shards (everything, via the async IO
+stack) and, under a ``RemoteIOEngine``, peers' stores (rows this worker
+does not own).  Placement is owned by a pluggable ``core.policy`` policy —
 static pre-sampling by default, online decayed-count or offline-oracle on
 request — and the tiers are *mutable*: ``refresh()`` promotes/demotes rows
 between device/host/storage through the existing ``AsyncIOEngine``
@@ -23,9 +24,10 @@ mutating in place) never corrupts an in-flight gather — the three tier
 gathers are issued storage first (longest latency), then host, then
 device, exactly the paper's overlap ordering.
 
-On real TPU hardware the device-tier gather is the Pallas kernel in
-``repro.kernels.gather``; here the jnp fallback is used and the Pallas
-kernel is validated in interpret mode by the kernel tests.
+The device-tier gather is an eager ``jnp.take`` on every backend, TPU
+included; the Pallas kernel in ``repro.kernels.gather`` has no caller
+here.  ``fused_backend="pallas"`` runs the fused lookup kernel of
+``repro.kernels.cache_lookup`` instead of the host plan.
 """
 from __future__ import annotations
 
@@ -333,7 +335,7 @@ def tier_rows(mode: str, n_vertices: int, device_frac: float,
 
 
 class HeteroCache:
-    """Policy-placed 3-tier feature cache with asynchronous tier migration
+    """Policy-placed 4-tier feature cache with asynchronous tier migration
     and (over a writable store) write-back mutable tiers: ``write_planned``
     updates resident rows in place and marks them dirty, dirty rows flush
     to storage on demotion or at a ``flush()`` barrier, and placement sees
@@ -512,7 +514,16 @@ class HeteroCache:
         device+host tier gather/scatter, and compacted miss-list emission —
         is one kernel launch (see kernels/cache_lookup/).  Returns the
         pre-gathered output rows so phase 2 becomes a no-op."""
+        from repro.kernels.cache_lookup.cache_lookup import (SMEM_BYTES,
+                                                             smem_bytes)
         from repro.kernels.cache_lookup.ops import fused_cache_lookup
+        need = smem_bytes(len(loc), len(ids))
+        if need > SMEM_BYTES:
+            raise ValueError(
+                f"fused_backend={self._fused_backend!r} cannot look up "
+                f"{len(ids)} ids over a {len(loc)}-row table: the kernel "
+                f"needs {need} bytes of scalar memory and a TPU v5e core "
+                f"has {SMEM_BYTES}; use fused_backend='host'")
         kout, fi, mid, mdst, rid, rdst, cnt = fused_cache_lookup(
             np.ascontiguousarray(ids), loc, slot, device_tier, host_tier,
             use_pallas=True,
@@ -600,7 +611,7 @@ class HeteroCache:
     @_traced("cache.gather.lookup")
     def lookup_planned(self, pg: PendingGather) -> None:
         """Phase 2: host-tier gather into the buffer + device-tier gather
-        issue (HBM-parallel; Pallas kernel on real TPU).  Idempotent."""
+        (an eager ``jnp.take``, dispatched asynchronously).  Idempotent."""
         import jax.numpy as jnp
         with pg._lk:
             if pg._looked:

@@ -21,7 +21,7 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Logical axis name -> mesh axis (or tuple of mesh axes).
 DEFAULT_RULES: dict[str, Any] = {
@@ -87,21 +87,25 @@ class ShardingCtx:
 _ACTIVE: list[ShardingCtx] = []
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The model code places arrays by
+    sharding constraints that the partitioner propagates; ``jax.make_mesh``
+    makes ``Explicit`` axes, under which every gather or reshape of a
+    sharded operand must name its output sharding."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 @contextmanager
 def use_mesh(mesh: Mesh, rules: dict | None = None):
-    ctx = ShardingCtx(mesh, {**DEFAULT_RULES, **(rules or {})})
+    ctx = ShardingCtx(auto_axes(mesh), {**DEFAULT_RULES, **(rules or {})})
     _ACTIVE.append(ctx)
     try:
-        has_use = hasattr(jax.sharding, "use_mesh")
-        with jax.sharding.use_mesh(mesh) if has_use else _null():
-            yield ctx
+        yield ctx
     finally:
         _ACTIVE.pop()
-
-
-@contextmanager
-def _null():
-    yield
 
 
 def current_ctx() -> ShardingCtx | None:

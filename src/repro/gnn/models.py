@@ -1,9 +1,9 @@
 """GraphSAGE [Hamilton+17] and GCN [Kipf&Welling16] on padded sampled blocks.
 
-Message passing uses segment-sum aggregation over static-shaped edge lists
-(the Pallas ``segment_agg`` kernel is the TPU hot-spot implementation; the
-jnp path below is the oracle it is tested against).  Hidden dim 256, 2 hops
-per the paper's setup.
+Message passing uses ``jax.ops.segment_sum`` aggregation over
+static-shaped edge lists on every backend, TPU included (the Pallas
+``segment_agg`` kernel is tested against it but has no caller).  Hidden
+dim 256, 2 hops per the paper's setup.
 """
 from __future__ import annotations
 

@@ -2,7 +2,7 @@
 
 Wires together every Helios component:
   topology  -> host tier (CSRGraph)
-  features  -> 3-tier HeteroCache over the FeatureStore ("SSDs")
+  features  -> 4-tier HeteroCache over the FeatureStore ("SSDs")
   IO        -> AsyncIOEngine (or Sync/CPU-managed baselines)
   schedule  -> PipelineExecutor with the deep GNN-aware operator plan
   compute   -> jit'd GraphSAGE/GCN step

@@ -1,9 +1,11 @@
 """Jitted public wrapper for the fused cache-lookup kernel.
 
-``use_pallas=True`` on real TPUs; the container validates the kernel in
-interpret mode (kernel tests and the ``HELIOS_FUSED_BACKEND`` CI leg).
-Empty cache tiers are padded with one zero row before dispatch — an empty
-tier has no ids mapped to it, so the pad row is never selected.
+``use_pallas=True`` runs the compiled kernel; the CPU test suite passes
+``interpret=True`` to run the same kernel in the Pallas interpreter.
+Cache tiers are padded with zero rows before dispatch (to one row for the
+jnp oracle, to a positive multiple of the kernel's row block for the
+kernel) — padding rows have no ids mapped to them, so they are never
+selected.
 """
 from __future__ import annotations
 
@@ -12,13 +14,19 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.cache_lookup.cache_lookup import fused_lookup
+from repro.kernels.cache_lookup.cache_lookup import ROWS, fused_lookup
 from repro.kernels.cache_lookup.ref import fused_lookup_ref
+
+
+def _pad_rows(tier, multiple: int):
+    n = tier.shape[0]
+    pad = max(multiple, -(-n // multiple) * multiple) - n
+    return jnp.pad(tier, ((0, pad), (0, 0))) if pad else tier
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def fused_cache_lookup(ids, loc, slot, device_tier, host_tier,
-                       use_pallas: bool = False, interpret: bool = True):
+                       use_pallas: bool = False, interpret: bool = False):
     """Fused lookup + dedup gather + miss-list emit; see cache_lookup.py
     for the 7-tuple output contract."""
     ids = jnp.asarray(ids, jnp.int32)
@@ -26,10 +34,8 @@ def fused_cache_lookup(ids, loc, slot, device_tier, host_tier,
     slot = jnp.asarray(slot, jnp.int32)
     dev = jnp.asarray(device_tier)
     host = jnp.asarray(host_tier)
-    if dev.shape[0] == 0:
-        dev = jnp.zeros((1, dev.shape[1]), dev.dtype)
-    if host.shape[0] == 0:
-        host = jnp.zeros((1, host.shape[1]), host.dtype)
     if use_pallas:
-        return fused_lookup(ids, loc, slot, dev, host, interpret=interpret)
-    return fused_lookup_ref(ids, loc, slot, dev, host)
+        return fused_lookup(ids, loc, slot, _pad_rows(dev, ROWS),
+                            _pad_rows(host, ROWS), interpret=interpret)
+    return fused_lookup_ref(ids, loc, slot, _pad_rows(dev, 1),
+                            _pad_rows(host, 1))

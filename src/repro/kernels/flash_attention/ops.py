@@ -12,7 +12,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 @partial(jax.jit, static_argnames=("causal", "use_pallas", "interpret"))
 def mha(q, k, v, causal: bool = True, use_pallas: bool = False,
-        interpret: bool = True):
+        interpret: bool = False):
     """q: (B, S, H, hd); k, v: (B, T, K, hd) with H % K == 0."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]

@@ -11,7 +11,7 @@ from repro.kernels.rwkv_scan.rwkv_scan import wkv_pallas
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "chunk"))
-def wkv(r, k, v, logw, u, use_pallas: bool = False, interpret: bool = True,
+def wkv(r, k, v, logw, u, use_pallas: bool = False, interpret: bool = False,
         chunk: int = 16):
     """r,k,v,logw: (BH, T, N) fp32; u: (BH, N)."""
     if use_pallas:

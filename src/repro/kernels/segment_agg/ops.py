@@ -12,7 +12,7 @@ from repro.kernels.segment_agg.segment_agg import segment_sum_pallas
 
 @partial(jax.jit, static_argnames=("n_segments", "use_pallas", "interpret"))
 def segment_sum(msgs, seg_ids, n_segments: int, use_pallas: bool = False,
-                interpret: bool = True):
+                interpret: bool = False):
     if use_pallas:
         return segment_sum_pallas(msgs, seg_ids, n_segments,
                                   interpret=interpret)
@@ -21,7 +21,7 @@ def segment_sum(msgs, seg_ids, n_segments: int, use_pallas: bool = False,
 
 @partial(jax.jit, static_argnames=("n_segments", "use_pallas", "interpret"))
 def segment_mean(msgs, seg_ids, n_segments: int, use_pallas: bool = False,
-                 interpret: bool = True):
+                 interpret: bool = False):
     s = segment_sum(msgs, seg_ids, n_segments, use_pallas, interpret)
     ones = jnp.ones((msgs.shape[0], 1), msgs.dtype)
     cnt = segment_sum(ones, seg_ids, n_segments, use_pallas, interpret)

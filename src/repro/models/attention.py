@@ -3,10 +3,10 @@
 Design notes (TPU):
   * Training/prefill never materialises the full (S, T) score matrix; a
     ``lax.scan`` over query chunks bounds the transient to (Cq, T) per head
-    group.  On real TPU hardware the Pallas flash-attention kernel
-    (``repro.kernels.flash_attention``) replaces this path; the XLA chunked
-    formulation is the portable reference and is what the multi-pod dry-run
-    lowers.
+    group.  This XLA chunked formulation runs on every backend and is
+    what the multi-pod dry-run lowers; the Pallas flash-attention kernel
+    (``repro.kernels.flash_attention``) is tested against it but has no
+    caller.
   * Local (windowed) attention slices the KV stream per query chunk, so the
     transient is (Cq, W + Cq) — this is what makes recurrentgemma's 1:2
     local-attention blocks cheap at 32k.

@@ -101,7 +101,6 @@ def _moe_block_dropless(x, p, mcfg: MoEConfig, act: str = "swiglu"):
     and psums over `model` (the same output reduction the dense path pays).
     No token-capacity drops up to the 2x-average overflow buffer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import current_ctx
 
@@ -166,11 +165,11 @@ def _moe_block_dropless(x, p, mcfg: MoEConfig, act: str = "swiglu"):
                   None, None)
         wspec = P("model", None, None)
         shspec = (jax.tree.map(lambda _: P(), sh) if sh is not None else None)
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=ctx.mesh,
             in_specs=(xspec, P(None, None), wspec, wspec, wspec, shspec),
             out_specs=(xspec, P(), P()),
-            check_rep=False)
+            check_vma=False)
         y, aux, z = fn(x, p["router"], we["w_gate"], we["w_up"], we["w_down"], sh)
     else:
         y, aux, z = local(x, p["router"], we["w_gate"], we["w_up"],

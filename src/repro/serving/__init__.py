@@ -2,7 +2,7 @@
 
 Request lifecycle: ``submit`` -> SLO-aware admission (``scheduler``) ->
 micro-batching with cross-request node dedup (``batcher``) -> one planned
-gather through the 3-tier ``HeteroCache`` -> jit'd forward step -> per
+gather through the 4-tier ``HeteroCache`` -> jit'd forward step -> per
 request scatter-back + latency accounting (``stats``).
 """
 from repro.serving.scheduler import (BULK, INTERACTIVE, PriorityClass,

@@ -36,9 +36,11 @@ def _batches(seed=0, n=4, dup=True):
 # ---------------------------------------------------------------------------
 
 def _tables(rng, n, frac_dev=0.2, frac_host=0.3, remote=False):
+    # the uncached rest splits 3:2 between storage and remote
+    rest = 1 - frac_dev - frac_host
     loc = rng.choice([0, 1, 2, 3] if remote else [0, 1, 2], n,
-                     p=[frac_dev, frac_host, 0.3, 0.2] if remote
-                     else [frac_dev, frac_host, 1 - frac_dev - frac_host])
+                     p=[frac_dev, frac_host, 0.6 * rest, 0.4 * rest] if remote
+                     else [frac_dev, frac_host, rest])
     loc = loc.astype(np.int32)
     slot = np.zeros(n, np.int64)
     for tier in (0, 1):
@@ -173,6 +175,19 @@ def test_fused_dedup_shrinks_io(store):
         eng.close()
     assert reqs["plan"][1] == reqs["host"][1]      # occurrence stats equal
     assert reqs["host"][0] * 4 <= reqs["plan"][0]  # IO requests deduped
+
+
+@pytest.mark.parametrize("n_rows,batch", [(1024, 43_009), (126_977, 8)])
+def test_pallas_backend_rejects_past_smem(tmp_path, n_rows, batch):
+    """The kernel's scalar memory cannot hold the tables and the batch:
+    the cache refuses with a ValueError before any compile or IO."""
+    big = FeatureStore(str(tmp_path / "big"), n_rows=n_rows, row_dim=4,
+                       n_shards=2, create=True)
+    eng = SyncIOEngine(big)
+    cache = HeteroCache(big, None, 16, 16, eng,
+                        fused_backend="pallas-interpret")
+    with pytest.raises(ValueError, match="scalar memory"):
+        cache.submit_planned(np.arange(batch) % n_rows)
 
 
 # ---------------------------------------------------------------------------
