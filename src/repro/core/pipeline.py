@@ -28,7 +28,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable
 
 from repro.core.simulator import VirtualClock
@@ -40,7 +40,7 @@ from repro.obs import trace as _trace
 class Operator:
     """One GPU-initiated operator in the execution plan."""
     name: str
-    fn: Callable[..., Any]
+    fn: Callable[..., Any]             # (ctx) -> None | {name: number}, summed
     resource: str                      # "io" | "host" | "device"
     deps: tuple = ()                   # names of ops in the same batch
     virtual_cost: Callable[..., float] | None = None  # returns seconds
@@ -51,6 +51,13 @@ class StageTiming:
     wall_s: float = 0.0
     virtual_s: float = 0.0
     calls: int = 0
+    # device-stream operators fed from the io/host pools only: host wall
+    # from the operator's turn on the one-thread device pool to its last
+    # input's completion, the device stream ready but starved of its batch
+    wait_s: float = 0.0
+    # per-call numbers an operator reports by returning a dict
+    # (batch_build's row and byte counts, the traced upload time)
+    sums: dict = field(default_factory=dict)
 
 
 class PipelineExecutor:
@@ -70,6 +77,11 @@ class PipelineExecutor:
         }
         self.timings: dict[str, StageTiming] = {op.name: StageTiming()
                                                 for op in plan}
+        # device-stream operators whose inputs come from another pool: the
+        # ones whose dependency wait is timed (and traced as pipe.wait.<op>)
+        where = {op.name: op.resource for op in plan}
+        self.starvable = {op.name for op in plan if op.resource == "device"
+                          and any(where[d] != "device" for d in op.deps)}
         self.clock = VirtualClock()
         self.virtual_end = 0.0
         # always-on virtual busy time per LOGICAL resource (op.resource even
@@ -78,17 +90,25 @@ class PipelineExecutor:
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------------
-    def _run_op(self, op: Operator, ctx: dict, batch_idx: int, ready_at: float):
+    def _run_op(self, op: Operator, ctx: dict, batch_idx: int, ready_at: float,
+                waited_from: float | None = None):
+        """Run one operator; ``waited_from`` is when its runner began
+        waiting on the operator's dependencies (``None``: not timed)."""
         t0 = time.perf_counter()
         out = op.fn(ctx)
         t1 = time.perf_counter()
         wall = t1 - t0
+        wait = t0 - waited_from if waited_from is not None else 0.0
         virt = op.virtual_cost(ctx) if op.virtual_cost else wall
         with self._lock:
             st = self.timings[op.name]
             st.wall_s += wall
+            st.wait_s += wait
             st.calls += 1
             st.virtual_s += virt
+            if out:
+                for k, v in out.items():
+                    st.sums[k] = st.sums.get(k, 0) + v
             resource = op.resource if self.mode != "nopipe" else "serial"
             end = self.clock.schedule(resource, ready_at, virt)
             self.virtual_end = max(self.virtual_end, end)
@@ -96,10 +116,15 @@ class PipelineExecutor:
                 self.resource_busy.get(op.resource, 0.0) + virt)
         tr = _trace.TRACER
         if tr is not None and tr.enabled:
+            if wait > 0.0:
+                # the dependency that finished last is the one waited for
+                on = max(op.deps, key=lambda d: ctx.get(f"__wall_end_{d}", 0.0))
+                tr.record(f"pipe.wait.{op.name}", waited_from, t0,
+                          track=op.resource, cat="wait",
+                          args={"batch": batch_idx, "on": on})
             tr.record(f"pipe.{op.name}", t0, t1, track=op.resource, cat="pipe",
-                      v0=end - virt, v1=end,
-                      args={"batch": batch_idx, "resource": op.resource,
-                            "deps": list(op.deps)})
+                      v0=end - virt, v1=end, args={"batch": batch_idx})
+            ctx[f"__wall_end_{op.name}"] = t1
         ctx[f"__end_{op.name}"] = end
         return out
 
@@ -118,10 +143,12 @@ class PipelineExecutor:
         done: dict[str, Future] = {}
 
         def runner(op: Operator):
+            waited_from = (time.perf_counter() if op.name in self.starvable
+                           else None)
             for d in op.deps:
                 done[d].result()
             ready = max([start_at] + [ends[d] for d in op.deps])
-            out = self._run_op(op, ctx, batch_idx, ready)
+            out = self._run_op(op, ctx, batch_idx, ready, waited_from)
             ends[op.name] = ctx[f"__end_{op.name}"]
             return out
 
@@ -167,7 +194,9 @@ class PipelineExecutor:
             "virtual_s": self.virtual_end,
             "virtual_per_batch_s": self.virtual_end / max(n_batches, 1),
             "stages": {k: {"wall_s": v.wall_s, "virtual_s": v.virtual_s,
-                           "calls": v.calls}
+                           "calls": v.calls, **v.sums,
+                           **({"wait_s": v.wait_s} if k in self.starvable
+                              else {})}
                        for k, v in self.timings.items()},
             "overlap": self.overlap_report(),
         }

@@ -30,6 +30,11 @@ class MiniBatch:
     blocks: list               # outer-to-inner hop blocks
     seeds: np.ndarray          # (B,) global ids
     labels: np.ndarray         # (B,)
+    n_real: int | None = None  # rows of real vertices (node_mask's count)
+
+    def __post_init__(self):
+        if self.n_real is None:
+            self.n_real = int(self.node_mask.sum())
 
     @property
     def all_nodes(self) -> np.ndarray:
@@ -95,7 +100,7 @@ class NeighborSampler:
             em[:k] = True
             blocks.append(Block(sp, dp, em, len(dst)))
         return MiniBatch(nodes_out, node_mask, blocks, seeds,
-                         self.g.labels[seeds])
+                         self.g.labels[seeds], len(nodes_arr))
 
     def _node_pad(self, batch: int) -> int:
         n = batch

@@ -320,16 +320,32 @@ class OutOfCoreGNNTrainer:
 
         def op_batch_build(ctx):
             mb = ctx["mb"]
-            ctx["feats"] = jnp.asarray(ctx["out"])
+            feats = ctx["feats"] = jnp.asarray(ctx["out"])
             ctx["tensors"] = (
                 tuple(jnp.asarray(b.src_pos) for b in mb.blocks),
                 tuple(jnp.asarray(b.dst_pos) for b in mb.blocks),
                 tuple(jnp.asarray(b.edge_mask) for b in mb.blocks),
                 jnp.asarray(mb.labels),
             )
+            # the batch's counters, summed per stage by the executor
+            return {"feature_rows": feats.shape[0], "real_rows": mb.n_real,
+                    "h2d_bytes": feats.nbytes + sum(
+                        a.nbytes for a in jax.tree.leaves(ctx["tensors"]))}
 
         def op_train(ctx):
             src, dst, em, labels = ctx["tensors"]
+            upload = None
+            tr = _trace.TRACER
+            if tr is not None and tr.enabled:
+                # traced runs only: wait out the feature upload before the
+                # dispatch, so the transfer is timed apart from the step
+                # (this serialises the dispatch, about 1 ms, behind it)
+                feats = ctx["feats"]
+                with tr.span("pipe.train.upload", track="device", cat="h2d",
+                             args={"batch": ctx["batch"],
+                                   "bytes": feats.nbytes}) as sp:
+                    feats.block_until_ready()
+                upload = {"upload_s": sp.wall_s}
             if cfg.train_embeddings:
                 self.state, m, fgrad = self.step_fn(self.state, ctx["feats"],
                                                     src, dst, em, labels)
@@ -339,6 +355,7 @@ class OutOfCoreGNNTrainer:
                                              dst, em, labels)
             ctx["metrics"] = jax.tree.map(float, m)
             self.metrics_log.append(ctx["metrics"])
+            return upload
 
         def op_embedding_writeback(ctx):
             # gradient-updated embedding rows ride the cache write path on
@@ -462,7 +479,7 @@ class OutOfCoreGNNTrainer:
             # assembly moves only index tensors; CPU-managed systems gather
             # the whole mini-batch into a staging buffer on the CPU and DMA
             # it across PCIe once more (paper I2, Fig. 1(b))
-            n_real = int(ctx["mb"].node_mask.sum())
+            n_real = ctx["mb"].n_real
             if cpu_managed:
                 nbytes = n_real * rb
                 return nbytes / HOST_STAGE_BW + pcie_time(nbytes)
@@ -514,7 +531,7 @@ class OutOfCoreGNNTrainer:
             # the seed stream reproducible in every pipeline mode
             rng = np.random.default_rng([cfg.seed, 0x5EED, i])
             seeds = draw_unique(rng, self.g.n_vertices, cfg.batch_size)
-            return {"seeds": seeds}
+            return {"seeds": seeds, "batch": i}
 
         out = pipe.run(make_ctx, n_batches)
         pipe.close()
@@ -582,14 +599,7 @@ class OutOfCoreGNNTrainer:
                      "bubble_frac": out["overlap"]["bubble_frac"]}
         tr = _trace.TRACER
         if tr is not None and tr.enabled:
-            # stats publish into the obs metrics registry (gauges), and
             # the traced span tree yields the full per-phase attribution
-            io_snap.publish("train.io")
-            cs_snap.publish("train.cache")
-            qs = getattr(self.io, "qwait_summary", None)
-            if qs is not None:
-                from repro.obs.metrics import publish_qwait
-                publish_qwait("train.io.qwait", qs())
             out["obs"] = _analyze.analyze_epoch(tr,
                                                 makespan=out["virtual_s"])
         if cfg.train_embeddings:

@@ -25,16 +25,24 @@ Design constraints (see ISSUE 9):
   spans that cross threads (engine workers parenting to the submit
   span via the completion object).
 
+* **Compiles placed.**  :func:`install` registers, once per process and
+  only once JAX has been imported, a ``jax.monitoring`` listener that
+  records each XLA backend compile as a ``jax.compile`` span (``args``
+  ``{"fn": <function name>}``) under the innermost open span of the
+  compiling thread.  It does nothing while no tracer is installed.
+
 ``HELIOS_TRACE=<path>`` in the environment installs a tracer at import
 time and registers an atexit Chrome-trace export, so any entry point —
 including an unmodified pytest run — can be traced without code
-changes.
+changes.  This module never is the first to import JAX, so a tracer
+installed before JAX was imported records no compiles.
 """
 from __future__ import annotations
 
 import atexit
 import itertools
 import os
+import sys
 import threading
 import time
 
@@ -211,10 +219,40 @@ def get_tracer():
     return TRACER
 
 
+#: The ``jax.monitoring`` event of one XLA backend compilation.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_compile(event, secs, fun_name=None, **_):
+    tr = TRACER
+    if tr is None or not tr.enabled or event != COMPILE_EVENT:
+        return
+    t1 = time.perf_counter()
+    tr.record("jax.compile", t1 - secs, t1, cat="compile",
+              parent=tr.current(), args={"fn": fun_name})
+
+
+def _listen_for_compiles():
+    """Register :func:`_on_compile` once per process, once JAX is imported."""
+    global _compile_listener_on
+    if "jax" not in sys.modules:
+        return
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listener_on = True
+
+
 def install(path=None):
     """Install (and return) a fresh global tracer."""
     global TRACER
     TRACER = Tracer(path)
+    _listen_for_compiles()
     return TRACER
 
 
