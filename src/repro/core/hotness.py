@@ -16,8 +16,11 @@ from repro.core.rng import draw_unique
 
 
 def presample_gnn(sampler, seeds_per_batch: int, n_batches: int,
-                  n_rows: int, seed: int = 0) -> np.ndarray:
-    """One pre-sampling epoch: counts vertex accesses under the sampler."""
+                  n_rows: int, seed: int = 0,
+                  batch_rows: list | None = None) -> np.ndarray:
+    """One pre-sampling epoch: counts vertex accesses under the sampler.
+    Each batch's count of distinct vertices is appended to ``batch_rows``
+    where one is given."""
     # decorrelated stream: with plain default_rng(seed) the draws below are
     # bit-identical to the trainer's own batch seeds (same seed, same
     # choice() call), handing placement oracle knowledge of the first
@@ -32,6 +35,8 @@ def presample_gnn(sampler, seeds_per_batch: int, n_batches: int,
         batch = sampler.sample(seeds)
         ids, c = np.unique(batch.all_nodes, return_counts=True)
         np.add.at(counts, ids, c)
+        if batch_rows is not None:
+            batch_rows.append(len(ids))
     return counts
 
 

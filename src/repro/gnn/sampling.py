@@ -1,8 +1,11 @@
 """Fanout neighbor sampling over CSR topology (paper: 2-hop, fanouts 25/10).
 
 Sampling runs on the host against the CPU-tier topology (the paper's
-neighbor-sampling operator); output blocks are padded to static shapes so
-the device-side training step is jit-stable across batches.
+neighbor-sampling operator).  The node array and the edge blocks are
+padded to their static maxima so the device-side step is jit-stable
+across batches; the real nodes come first, and every edge indexes one of
+them.  The feature array is not padded here: the trainer sizes it to a row
+bucket that holds the batch's real rows (``gnn.train.row_bucket``).
 """
 from __future__ import annotations
 

@@ -40,6 +40,19 @@ from repro.obs import trace as _trace
 from repro.train.optim import adamw
 
 
+# a batch's feature array holds a rung of this ladder of rows, not the
+# sampler's worst case: rows past the batch's real ones are zeros that no
+# edge and no loss reads, so the rungs bound the step's compiles while the
+# upload stays near the real rows
+ROW_HEADROOM = 1.05        # over the presample epoch's largest batch
+
+
+def row_bucket(n: int) -> int:
+    """Smallest rung of 2^k x {1, 1.25, 1.5, 1.75} that holds ``n`` rows."""
+    base = 1 << max((max(n, 1) - 1).bit_length() - 1, 2)
+    return next(base * q // 4 for q in (4, 5, 6, 7, 8) if base * q // 4 >= n)
+
+
 @dataclass
 class TrainerConfig:
     model: str = "sage"            # sage | gcn
@@ -206,10 +219,17 @@ class OutOfCoreGNNTrainer:
         # --- hotness pre-sampling + cache placement (paper §3.2.2) -------
         # presample on a SEPARATE sampler so the training sampler's rng
         # stream doesn't depend on the presample configuration
+        presampled = []
         hot = hotness_mod.presample_gnn(
             NeighborSampler(graph, cfg.fanouts, cfg.seed + 1),
             cfg.batch_size, cfg.presample_batches,
-            graph.n_vertices, cfg.seed)
+            graph.n_vertices, cfg.seed, batch_rows=presampled)
+        # the feature rows each batch uploads: seeded from the presample
+        # epoch so the step compiles before training, raised (never
+        # lowered) when a batch's real rows pass it
+        self._rows = row_bucket(int(max(presampled, default=0)
+                                    * ROW_HEADROOM))
+        self._rows_lock = threading.Lock()
         dev_rows, host_rows = tier_rows(cfg.mode, graph.n_vertices,
                                         cfg.device_cache_frac,
                                         cfg.host_cache_frac)
@@ -291,8 +311,14 @@ class OutOfCoreGNNTrainer:
         # HeteroCache's split-phase API — the operators only phase it
         def op_io_submit(ctx):
             mb = ctx["mb"]
+            cap = len(mb.nodes)
+            with self._rows_lock:
+                want = min(row_bucket(mb.n_real), cap)
+                ctx["bucket_rose"] = want > self._rows
+                self._rows = max(self._rows, want)
+                n_rows = min(self._rows, cap)
             ctx["pending"] = self.cache.submit_planned(mb.all_nodes,
-                                                       n_rows=len(mb.nodes))
+                                                       n_rows=n_rows)
 
         def op_cache_lookup(ctx):
             self.cache.lookup_planned(ctx["pending"])
@@ -319,6 +345,9 @@ class OutOfCoreGNNTrainer:
                 ctx["prefetch"] = self.cache.complete_prefetch(prev)
 
         def op_batch_build(ctx):
+            # the feature array holds the batch's row bucket (its real rows,
+            # then zeros); the index, mask and label tensors keep the
+            # sampler's static maxima, and every index is below n_real
             mb = ctx["mb"]
             feats = ctx["feats"] = jnp.asarray(ctx["out"])
             ctx["tensors"] = (
@@ -330,7 +359,8 @@ class OutOfCoreGNNTrainer:
             # the batch's counters, summed per stage by the executor
             return {"feature_rows": feats.shape[0], "real_rows": mb.n_real,
                     "h2d_bytes": feats.nbytes + sum(
-                        a.nbytes for a in jax.tree.leaves(ctx["tensors"]))}
+                        a.nbytes for a in jax.tree.leaves(ctx["tensors"])),
+                    "bucket_rises": int(ctx["bucket_rose"])}
 
         def op_train(ctx):
             src, dst, em, labels = ctx["tensors"]
@@ -366,13 +396,13 @@ class OutOfCoreGNNTrainer:
             # and completes the one the previous batch left pending, so
             # the storage write hides under a whole batch of other work
             mb = ctx["mb"]
-            mask = mb.node_mask
+            n = mb.n_real
             # the RMW read inside apply_grads blocks on a storage ticket —
             # keep it OUTSIDE _pf_lock so the prefetch operator (which
             # contends on the same lock for its double-buffer swap) never
             # serializes behind it
-            pw = self.embeddings.apply_grads(mb.nodes[mask],
-                                             ctx["feat_grad"][mask],
+            pw = self.embeddings.apply_grads(mb.nodes[:n],
+                                             ctx["feat_grad"][:n],
                                              wait=False)
             with self._pf_lock:
                 prev, self._wb_pending = self._wb_pending, pw

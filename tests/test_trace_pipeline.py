@@ -12,7 +12,7 @@ import pytest
 
 from repro.core.iostack import FeatureStore
 from repro.gnn.graph import synth_graph
-from repro.gnn.train import OutOfCoreGNNTrainer, TrainerConfig
+from repro.gnn.train import OutOfCoreGNNTrainer, TrainerConfig, row_bucket
 from repro.obs import trace as obs_trace
 
 ROW_DIM = 32
@@ -73,8 +73,10 @@ def test_one_wait_and_one_upload_span_per_batch(traced):
         assert w.t1 > w.t0 and w.track == "device"
         # the wait ends where the operator starts
         assert w.t1 == builds[w.args["batch"]].t0
-    n_pad = len(drawn[0].nodes)
-    assert all(u.args["bytes"] == n_pad * ROW_DIM * 4 for u in uploads)
+    # each upload is a rung of the row ladder, not the sampler's worst case
+    for u in uploads:
+        rows, rest = divmod(u.args["bytes"], ROW_DIM * 4)
+        assert rest == 0 and rows == row_bucket(rows) < len(drawn[0].nodes)
 
 
 def test_operator_spans_carry_the_batch_only(traced):
@@ -83,29 +85,39 @@ def test_operator_spans_carry_the_batch_only(traced):
     assert ops and all(set(s.args) == {"batch"} for s in ops)
 
 
-def _h2d_bytes(mb):
+def _tensor_bytes(mb):
+    """Device bytes of a batch's index, mask and label tensors."""
     def dev_bytes(a):
         return a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
     tensors = ([b.src_pos for b in mb.blocks] + [b.dst_pos for b in mb.blocks]
                + [b.edge_mask for b in mb.blocks] + [mb.labels])
-    return len(mb.nodes) * ROW_DIM * 4 + sum(dev_bytes(a) for a in tensors)
+    return sum(dev_bytes(a) for a in tensors)
 
 
 def _counters(out):
     bb = out["stages"]["batch_build"]
     return {k: bb[k] for k in ("calls", "feature_rows", "real_rows",
-                               "h2d_bytes")}
+                               "h2d_bytes", "bucket_rises")}
 
 
 def test_batch_counters_match_the_sampled_batches(traced):
-    _, out, drawn = traced
+    tr, out, drawn = traced
     assert len(drawn) == 4
-    assert _counters(out) == {
-        "calls": 4,
-        "feature_rows": sum(len(mb.nodes) for mb in drawn),
-        "real_rows": sum(int(np.count_nonzero(mb.node_mask)) for mb in drawn),
-        "h2d_bytes": sum(_h2d_bytes(mb) for mb in drawn)}
-    assert _counters(out)["real_rows"] < _counters(out)["feature_rows"]
+    c = _counters(out)
+    real = sorted(int(np.count_nonzero(mb.node_mask)) for mb in drawn)
+    rows = sorted(u.args["bytes"] // (ROW_DIM * 4)
+                  for u in _named(tr, "pipe.train.upload"))
+    assert c["calls"] == 4 and c["real_rows"] == sum(real)
+    # each batch's feature rows are a rung of the ladder holding its real
+    # rows, and at most the sampler's padded node count
+    assert c["feature_rows"] == sum(rows)
+    assert all(r == row_bucket(r) for r in rows)
+    assert all(r >= n for r, n in zip(rows, real))
+    assert (c["real_rows"] <= c["feature_rows"]
+            <= sum(len(mb.nodes) for mb in drawn))
+    assert c["h2d_bytes"] == (c["feature_rows"] * ROW_DIM * 4
+                              + sum(_tensor_bytes(mb) for mb in drawn))
+    assert c["bucket_rises"] == 0     # seeded by the presample epoch
 
 
 def test_stage_report_holds_waits_and_sums(traced):
