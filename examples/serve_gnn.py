@@ -13,6 +13,7 @@ import tempfile
 from repro import compile_cache
 from repro.core.iostack import FeatureStore
 from repro.gnn.graph import synth_graph
+from repro.gnn.models import MODELS
 from repro.serving import GNNInferenceServer, ServerConfig, zipf_workload
 
 
@@ -23,7 +24,7 @@ def main():
                     help="open-loop arrival rate (virtual req/s)")
     ap.add_argument("--vertices", type=int, default=30_000)
     ap.add_argument("--dim", type=int, default=512)
-    ap.add_argument("--model", default="sage", choices=["sage", "gcn"])
+    ap.add_argument("--model", default="sage", choices=MODELS)
     ap.add_argument("--seeds-per-request", type=int, default=32)
     ap.add_argument("--cache-policy", default="static",
                     choices=["static", "online"],

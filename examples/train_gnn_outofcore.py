@@ -12,6 +12,7 @@ import tempfile
 from repro import compile_cache
 from repro.core.iostack import FeatureStore
 from repro.gnn.graph import synth_graph
+from repro.gnn.models import MODELS
 from repro.gnn.train import OutOfCoreGNNTrainer, TrainerConfig
 
 
@@ -20,7 +21,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--vertices", type=int, default=50_000)
     ap.add_argument("--dim", type=int, default=128)
-    ap.add_argument("--model", default="sage", choices=["sage", "gcn"])
+    ap.add_argument("--model", default="sage", choices=MODELS)
     ap.add_argument("--train-embeddings", action="store_true",
                     help="treat the feature rows as trainable embeddings: "
                          "gradient updates ride the cache write-back tiers "

@@ -5,7 +5,7 @@ Wires together every Helios component:
   features  -> 4-tier HeteroCache over the FeatureStore ("SSDs")
   IO        -> AsyncIOEngine (or Sync/CPU-managed baselines)
   schedule  -> PipelineExecutor with the deep GNN-aware operator plan
-  compute   -> jit'd GraphSAGE/GCN step
+  compute   -> jit'd GraphSAGE/GCN/GAT step
 
 ``mode`` selects the system under test for the paper's ablations:
   helios        deep pipeline + async IO + hetero cache
@@ -33,7 +33,8 @@ from repro.core.simulator import (DEFAULT_ENVELOPE, HOST_STAGE_BW,
                                   MATMUL_RATE, SAMPLE_RATE_CPU,
                                   SAMPLE_RATE_DEVICE, pcie_time)
 from repro.gnn.graph import CSRGraph
-from repro.gnn.models import init_gnn_params, make_gnn_train_step
+from repro.gnn.models import (edge_counts, init_gnn_params,
+                              make_gnn_train_step)
 from repro.gnn.sampling import NeighborSampler, draw_unique
 from repro.obs import analyze as _analyze
 from repro.obs import trace as _trace
@@ -55,7 +56,7 @@ def row_bucket(n: int) -> int:
 
 @dataclass
 class TrainerConfig:
-    model: str = "sage"            # sage | gcn
+    model: str = "sage"            # sage | gcn | gat
     hidden: int = 256
     batch_size: int = 1024
     fanouts: tuple = (25, 10)
@@ -360,7 +361,9 @@ class OutOfCoreGNNTrainer:
             return {"feature_rows": feats.shape[0], "real_rows": mb.n_real,
                     "h2d_bytes": feats.nbytes + sum(
                         a.nbytes for a in jax.tree.leaves(ctx["tensors"])),
-                    "bucket_rises": int(ctx["bucket_rose"])}
+                    "bucket_rises": int(ctx["bucket_rose"]),
+                    **edge_counts(cfg.model, mb.blocks, feats.shape[0],
+                                  mb.n_real)}
 
         def op_train(ctx):
             src, dst, em, labels = ctx["tensors"]

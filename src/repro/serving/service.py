@@ -44,7 +44,7 @@ from repro.serving.stats import ServingStats
 
 @dataclass
 class ServerConfig:
-    model: str = "sage"                # sage | gcn
+    model: str = "sage"                # sage | gcn | gat
     hidden: int = 256
     request_batch_size: int = 64       # seeds per request (padded to this)
     fanouts: tuple = (10, 5)

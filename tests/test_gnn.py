@@ -47,7 +47,7 @@ def test_sampler_edges_valid(graph):
     assert set(np.unique(b0.dst_pos[b0.edge_mask])) <= set(range(32))
 
 
-@pytest.mark.parametrize("model", ["sage", "gcn"])
+@pytest.mark.parametrize("model", ["sage", "gcn", "gat"])
 def test_gnn_loss_grad(model, graph):
     s = NeighborSampler(graph, fanouts=(4, 3), seed=2)
     seeds = np.arange(16)

@@ -58,7 +58,7 @@ def _record(trn):
     return mbs, fed
 
 
-@pytest.mark.parametrize("model", ["sage", "gcn"])
+@pytest.mark.parametrize("model", ["sage", "gcn", "gat"])
 def test_step_on_bucket_matches_full_padded_batch(graph, model):
     mb = NeighborSampler(graph, (4, 3), seed=5).sample(
         draw_unique(np.random.default_rng(6), N_VERTICES, 64))

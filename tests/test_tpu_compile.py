@@ -1,6 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e chip at the shapes
 ``chip_smoke.py`` runs: the GNN train and infer steps and the fused
-cache-lookup kernel.  Nothing runs; the TPU compiler refuses here what the
+cache-lookup kernel; and the GAT train step at the ``gat-pa`` benchmark
+configuration's widths.  Nothing runs; the TPU compiler refuses here what the
 chip would refuse (block tiling, scalar memory, device memory)."""
 import os
 
@@ -102,6 +103,24 @@ def test_infer_step_compiles_and_fits(one_chip):
     compiled = make_gnn_infer_step("sage", 64).lower(
         _sds(_params(), one_chip), feats, src, dst, em).compile()
     assert feats.size * 4 < _device_bytes(compiled) < HBM_BYTES
+
+
+def test_gat_train_step_compiles_and_fits(one_chip):
+    """GAT at PA's widths: 128-wide rows, 4 heads x 256, 172 classes, a
+    49,152-row bucket and the sampler's (25,600, 256,000) edge slots."""
+    opt = adamw(1e-3)
+    params = jax.eval_shape(lambda: init_gnn_params(
+        jax.random.key(0), "gat", 128, 1024, 172))
+    state = jax.eval_shape(lambda p: {"params": p, "opt": opt.init(p)},
+                           params)
+    _, src, dst, em, labels = _batch(1024, one_chip)
+    feats = jax.ShapeDtypeStruct((49_152, 128), jnp.float32,
+                                 sharding=one_chip)
+    with jax.default_matmul_precision("highest"):
+        compiled = make_gnn_train_step("gat", opt, 1024).lower(
+            _sds(state, one_chip), feats, src, dst, em, labels).compile()
+    # at least one 1024-wide message per edge slot, and under the chip
+    assert 256_000 * 1024 * 4 < _device_bytes(compiled) < HBM_BYTES
 
 
 def _lookup_args(n, b, sharding):
