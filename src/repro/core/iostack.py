@@ -471,6 +471,13 @@ def coalesce_offsets(offsets: np.ndarray, gap: int):
     return order, bounds
 
 
+def _span_rows(so: np.ndarray, bounds: np.ndarray) -> int:
+    """Rows the coalesced ranges of sorted offsets ``so`` cover, gap waste
+    included (``bounds`` as ``coalesce_offsets`` returns it)."""
+    lo, hi = bounds[:-1], bounds[1:]
+    return int((so[hi - 1] + 1 - so[lo]).sum())
+
+
 ADAPTIVE_GAP = "adaptive"               # coalesce_gap sentinel
 
 
@@ -900,10 +907,13 @@ class AsyncIOEngine:
     thread-level parallel striping over per-SSD SQs.  Inside each shard's
     service loop, requested rows are sorted by offset and near-adjacent rows
     (``coalesce_gap`` unrequested rows or fewer between them) merge into
-    sequential memmap range reads, turning random feature misses into
-    streamed ranges (DiskGNN's batched-read lever).  The ticket aggregates
-    per-shard completions; its virtual time is the max over shards, bounded
-    below by the PCIe crossing.
+    sequential ranges, turning random feature misses into streamed ranges
+    (DiskGNN's batched-read lever).  The ranges shape only the modelled
+    device's cost (commands issued, span bytes); the host copy is one
+    gather per shard batch, in offset order, so its Python cost is
+    O(shards), not O(ranges).  The ticket aggregates per-shard completions;
+    its virtual time is the max over shards, bounded below by the PCIe
+    crossing.
 
     ``worker_budget`` is the fraction of the executor's cores granted to the
     IO stack (paper: 32 thread blocks ~= 30%); queue depth per shard follows
@@ -1159,11 +1169,8 @@ class AsyncIOEngine:
         mm = self.store.shards[shard]
         order, bounds = coalesce_offsets(offs, self._gap_for(offs))
         so, sd = offs[order], dest[order]
-        spans = [(int(so[lo]), int(so[hi - 1]) + 1, lo, hi)
-                 for lo, hi in zip(bounds[:-1], bounds[1:])]
         n_ranges = len(bounds) - 1
-        span_rows = sum(end - start for start, end, _, _ in spans)
-        span_bytes = span_rows * self.store.row_bytes
+        span_bytes = _span_rows(so, bounds) * self.store.row_bytes
         # per-SSD queue depth under the worker budget (32 blocks ~ 30% of
         # cores keep ~256 commands in flight per device; below that the
         # device starves — paper Fig. 7)
@@ -1174,10 +1181,10 @@ class AsyncIOEngine:
 
         def io_fn(fd):
             # runs once, on the successful attempt: retried reads return
-            # bit-identical bytes no matter how many attempts failed
-            for start, end, lo, hi in spans:
-                block = mm[start:end]   # sequential slice, not fancy-index
-                buf[sd[lo:hi]] = block[so[lo:hi] - start]
+            # bit-identical bytes no matter how many attempts failed.  One
+            # gather in offset order (the copy walks the shard file
+            # forward); the plain ndarray view skips memmap's Python hooks
+            buf[sd] = np.asarray(mm)[so]
 
         virt, _, _ = _recover_op(self, shard, "r", time_fn, io_fn)
         return virt, n_ranges, span_bytes
@@ -1194,10 +1201,8 @@ class AsyncIOEngine:
         mm = self.store.shards[shard]
         order, bounds = coalesce_offsets(offs, self._gap_for(offs))
         so, sr = offs[order], rows[order]
-        span_rows = sum(int(so[hi - 1]) + 1 - int(so[lo])
-                        for lo, hi in zip(bounds[:-1], bounds[1:]))
         n_ranges = len(bounds) - 1
-        span_bytes = span_rows * self.store.row_bytes
+        span_bytes = _span_rows(so, bounds) * self.store.row_bytes
         qd = int(256 * min(1.0, self.worker_budget / 0.3))
 
         def time_fn(attempt, hedged):

@@ -96,6 +96,101 @@ def test_striped_gather_matches_read_rows(store, gap):
     eng.close()
 
 
+def _shard_ids(shard, offsets):
+    return np.asarray(offsets, np.int64) * N_SHARDS + shard
+
+
+_GATHER_CASES = {
+    # duplicates inside one shard and across shards
+    "duplicates": np.concatenate([_shard_ids(0, [5, 5, 9, 5, 300]),
+                                  _shard_ids(2, [7, 7, 7, 1])]),
+    # adjacent runs, runs split by gaps of 1..12 rows, shuffled
+    "runs": np.random.default_rng(3).permutation(np.concatenate(
+        [_shard_ids(1, np.r_[0:40, 41:60, 70:80, 92:93, 200:230]),
+         _shard_ids(3, np.r_[10:20, 29:31, 500:520])])),
+    # one row on shard 0, one on shard 3, shards 1 and 2 empty
+    "one_row_shards": np.concatenate([_shard_ids(0, [17]),
+                                      _shard_ids(3, [N_ROWS // N_SHARDS - 1])]),
+}
+
+
+@pytest.mark.parametrize("gap", [0, 8, "adaptive"])
+@pytest.mark.parametrize("case", sorted(_GATHER_CASES))
+def test_striped_gather_rows_and_accounting_exact(store, case, gap):
+    """The per-shard gather returns exactly ``read_rows``' bytes, and the
+    modelled device sees exactly the ranges ``coalesce_offsets`` forms:
+    ``IOStats.ranges``, ``span_bytes`` and the ticket's virtual seconds are
+    reckoned here, independently, from the same offsets."""
+    from repro.core.iostack import pick_coalesce_gap
+    from repro.core.simulator import SSDModel
+    ids = _GATHER_CASES[case]
+    eng = AsyncIOEngine(store, coalesce_gap=gap, chaos=None)
+    ssd = SSDModel(eng.env)
+    qd = int(256 * min(1.0, eng.worker_budget / 0.3))
+    sid, off = store.locate(ids)
+    want_ranges = want_span = 0
+    want_virt = 0.0
+    for s in np.unique(sid):
+        offs = off[sid == s]
+        g = (pick_coalesce_gap(offs, eng.max_coalesce_gap, eng.amp_cap)
+             if gap == "adaptive" else gap)
+        rs = _ranges(offs, g)
+        span = sum(e - b for b, e in rs) * store.row_bytes
+        want_ranges += len(rs)
+        want_span += span
+        want_virt = max(want_virt, ssd.range_io_time(len(rs), span, qd))
+    want_virt = max(want_virt, want_span / eng.env.pcie_bw)
+    want_rows = store.read_rows(ids)
+
+    data, virt = eng.submit(ids).wait()
+    np.testing.assert_array_equal(data, want_rows)
+    assert virt == want_virt
+    # scatter form: caller-provided buffer, shuffled destination rows
+    out = np.full((len(ids) + 3, ROW_DIM), -1, store.dtype)
+    dest = np.random.default_rng(4).permutation(len(ids)) + 3
+    _, virt2 = eng.submit(ids, out, dest).wait()
+    np.testing.assert_array_equal(out[dest], want_rows)
+    assert (out[:3] == -1).all()                    # untouched rows stay
+    assert virt2 == want_virt
+    st = eng.stats
+    assert st.ranges == 2 * want_ranges
+    assert st.span_bytes == 2 * want_span
+    assert st.virtual_io_s == pytest.approx(2 * want_virt, rel=1e-12)
+    eng.close()
+
+
+@pytest.mark.parametrize("chaos", [None, "transient"])
+def test_striped_read_gathers_once_per_shard_batch(store, monkeypatch, chaos):
+    """Regression guard: the host copy is one gather per shard batch, never
+    one memmap slice per coalesced range — with or without retried
+    transient faults, and still bit-exact."""
+    from repro.ft.chaos import ChaosSchedule
+    ids = np.random.default_rng(5).choice(N_ROWS, 3000, replace=False)
+    want = store.read_rows(ids)
+    ch = (ChaosSchedule(seed=11, read_error_rate=0.3)
+          if chaos == "transient" else None)
+    eng = AsyncIOEngine(store, coalesce_gap=0, chaos=ch)
+    calls = []
+    getitem = np.memmap.__getitem__
+
+    def counting(self, index):
+        calls.append(1)
+        return getitem(self, index)
+
+    monkeypatch.setattr(np.memmap, "__getitem__", counting)
+    reads = 4 if chaos else 1
+    for _ in range(reads):
+        data, _ = eng.submit(ids).wait()
+        np.testing.assert_array_equal(data, want)
+    monkeypatch.undo()
+    st = eng.stats
+    assert st.ranges > 10 * st.shard_batches       # scattered: many ranges
+    assert len(calls) <= st.shard_batches
+    if chaos:
+        assert st.retries > 0 and st.transient_errors > 0
+    eng.close()
+
+
 def test_striped_coalesced_beats_legacy_2x_on_skew(store):
     """Acceptance: >=2x effective storage bandwidth (virtual time) over the
     PR-2 single-queue path on a skewed workload."""
