@@ -320,7 +320,7 @@ class PendingGather:
 
 def tier_rows(mode: str, n_vertices: int, device_frac: float,
               host_frac: float) -> tuple:
-    """Per-mode cache tier sizing (shared by trainer and server):
+    """Per-mode cache tier sizing (the inference server's modes):
     GIDS keeps a device-only BaM cache, CPU-managed systems a host-only
     staging buffer, ``helios-nocache`` ablates both."""
     dev_rows = int(n_vertices * device_frac)

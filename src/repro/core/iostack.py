@@ -1700,7 +1700,7 @@ def make_engine(mode: str, store: FeatureStore, worker_budget: float = 0.3,
                 qwait_high_s: float | None = None,
                 qwait_low_s: float | None = None,
                 sched_log: bool = False):
-    """Engine for an ablation mode (shared by trainer and server):
+    """Engine for one of the inference server's modes:
     ``cpu`` -> CPUManagedEngine, ``gids`` -> SyncIOEngine, anything
     Helios-flavoured -> AsyncIOEngine (``striped``/``coalesce_gap`` tune
     the per-shard SQ read path; ``coalesce_gap="adaptive"`` re-picks the

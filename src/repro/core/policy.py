@@ -416,7 +416,7 @@ def make_policy(kind: str, n_rows: int,
                 presample: np.ndarray | None = None, trace=None,
                 refresh_every: int = 8, half_life: float = 16.0,
                 hysteresis: float = 0.1) -> CachePolicy:
-    """Policy factory shared by the trainer, the server, and benchmarks."""
+    """Policy factory shared by the trainer and the server."""
     if kind == "static":
         return StaticPresamplePolicy(
             np.zeros(n_rows) if presample is None else presample)

@@ -1,14 +1,16 @@
 """Calibrated storage/interconnect timing model (paper hardware envelope).
 
-The container has no NVMe SSDs or PCIe switches, so benchmarks impose the
-paper's hardware characteristics on the memory-mapped storage tier: per-SSD
-sequential bandwidth and IOPS ceilings (Intel P5510-class), PCIe 4.0x16
-host<->device bandwidth, and HBM-class cache bandwidth.  The simulator is
-*deterministic* given a request trace — benchmark ratios (Figs. 5-11) are
-reproduced structurally rather than by CPU wall-clock accident.
+The host has no NVMe SSD array or PCIe switches, so the IO engines price
+their reads and writes with the paper's hardware characteristics over the
+memory-mapped storage tier: per-SSD sequential bandwidth and IOPS
+ceilings (Intel P5510-class), PCIe 4.0x16 host<->device bandwidth, and
+HBM-class cache bandwidth.  The model is *deterministic* given a request
+trace.
 
-Times are virtual seconds; engines advance a virtual clock per completed
-request batch.  Wall-clock numbers are reported alongside for transparency.
+Times are virtual seconds, the modelled cost of hardware the host does
+not have; the engines and the cache report them as such, beside the work
+they really do.  The serving loop also schedules on a ``VirtualClock``
+with the operator constants below.
 """
 from __future__ import annotations
 
@@ -38,8 +40,8 @@ class HardwareEnvelope:
 
 DEFAULT_ENVELOPE = HardwareEnvelope()
 
-# Calibrated operator cost constants shared by the trainer and the
-# inference server (one source: recalibrating here moves both).
+# Calibrated operator cost constants of the inference server's virtual
+# schedule.
 SAMPLE_RATE_DEVICE = 2e9       # bytes/s of edge data, device-managed sampling
 SAMPLE_RATE_CPU = 0.04e9       # CPU-managed sampling+batch build (paper I1)
 MATMUL_RATE = 60e12            # flops/s device matmul throughput

@@ -3,21 +3,16 @@
 Wires together every Helios component:
   topology  -> host tier (CSRGraph)
   features  -> 4-tier HeteroCache over the FeatureStore ("SSDs")
-  IO        -> AsyncIOEngine (or Sync/CPU-managed baselines)
+  IO        -> AsyncIOEngine (per-shard queues, decoupled completion)
   schedule  -> PipelineExecutor with the deep GNN-aware operator plan
   compute   -> jit'd GraphSAGE/GCN/GAT step
 
-``mode`` selects the system under test for the paper's ablations:
-  helios        deep pipeline + async IO + hetero cache
-  helios-nopipe serial operators (Fig. 11)
-  helios-nocache no device/host feature cache (Figs. 8/9)
-  gids          sync coupled IO, device-only cache (Fig. 5)
-  cpu           CPU-managed staging (Ginex/MariusGNN-like, Fig. 5)
+``train`` reports the host's wall clock: the run's ``wall_s`` and each
+operator's stage timing, plus the cache and IO counters.
 """
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass
 
 import jax
@@ -25,18 +20,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hotness as hotness_mod
-from repro.core.hetero_cache import HeteroCache, tier_rows
-from repro.core.iostack import FeatureStore, make_engine
+from repro.core.hetero_cache import HeteroCache
+from repro.core.iostack import AsyncIOEngine, FeatureStore
 from repro.core.pipeline import Operator, PipelineExecutor
 from repro.core.policy import make_policy
-from repro.core.simulator import (DEFAULT_ENVELOPE, HOST_STAGE_BW,
-                                  MATMUL_RATE, SAMPLE_RATE_CPU,
-                                  SAMPLE_RATE_DEVICE, pcie_time)
 from repro.gnn.graph import CSRGraph
 from repro.gnn.models import (edge_counts, init_gnn_params,
                               make_gnn_train_step)
 from repro.gnn.sampling import NeighborSampler, draw_unique
-from repro.obs import analyze as _analyze
 from repro.obs import trace as _trace
 from repro.train.optim import adamw
 
@@ -60,7 +51,7 @@ class TrainerConfig:
     hidden: int = 256
     batch_size: int = 1024
     fanouts: tuple = (25, 10)
-    mode: str = "helios"
+    mode: str = "helios"           # the only system; kept for configs
     device_cache_frac: float = 0.05
     host_cache_frac: float = 0.10
     prefetch_depth: int = 2
@@ -115,6 +106,11 @@ class TrainerConfig:
     io_qwait_high_s: float | None = None
     io_qwait_low_s: float | None = None
     seed: int = 0
+
+    def __post_init__(self):
+        if self.mode != "helios":
+            raise ValueError(f"TrainerConfig.mode must be 'helios', got "
+                             f"{self.mode!r}")
 
     def retry_policy(self):
         from repro.ft.chaos import DEFAULT_RETRY, RetryPolicy
@@ -209,13 +205,7 @@ class OutOfCoreGNNTrainer:
                              "the parameters)")
         self.sampler = NeighborSampler(graph, cfg.fanouts, cfg.seed)
 
-        # --- IO engine per mode ------------------------------------------
-        self.io = make_engine(cfg.mode, store, cfg.io_worker_budget,
-                              chaos=cfg.chaos, retry=cfg.retry_policy(),
-                              sched=cfg.io_sched,
-                              class_weights=cfg.io_class_weights,
-                              qwait_high_s=cfg.io_qwait_high_s,
-                              qwait_low_s=cfg.io_qwait_low_s)
+        self.io = self._engine(store)
 
         # --- hotness pre-sampling + cache placement (paper §3.2.2) -------
         # presample on a SEPARATE sampler so the training sampler's rng
@@ -231,9 +221,8 @@ class OutOfCoreGNNTrainer:
         self._rows = row_bucket(int(max(presampled, default=0)
                                     * ROW_HEADROOM))
         self._rows_lock = threading.Lock()
-        dev_rows, host_rows = tier_rows(cfg.mode, graph.n_vertices,
-                                        cfg.device_cache_frac,
-                                        cfg.host_cache_frac)
+        dev_rows = int(graph.n_vertices * cfg.device_cache_frac)
+        host_rows = int(graph.n_vertices * cfg.host_cache_frac)
         policy = make_policy(cfg.cache_policy, graph.n_vertices,
                              presample=hot, refresh_every=cfg.refresh_every,
                              half_life=cfg.policy_half_life,
@@ -263,13 +252,7 @@ class OutOfCoreGNNTrainer:
                               n_shards=store.n_shards,
                               create=True, writable=True)
             c = HeteroCache(
-                st, None, 0, host_rows,
-                make_engine(cfg.mode, st, cfg.io_worker_budget,
-                            chaos=cfg.chaos, retry=cfg.retry_policy(),
-                            sched=cfg.io_sched,
-                            class_weights=cfg.io_class_weights,
-                            qwait_high_s=cfg.io_qwait_high_s,
-                            qwait_low_s=cfg.io_qwait_low_s),
+                st, None, 0, host_rows, self._engine(st),
                 write_policy=cfg.write_policy,
                 write_combine_rows=cfg.write_combine_rows,
                 fused=cfg.fused_lookup)
@@ -300,10 +283,18 @@ class OutOfCoreGNNTrainer:
         # in flight until batch i+1's operator completes it
         self._wb_pending = None
 
+    def _engine(self, store: FeatureStore) -> AsyncIOEngine:
+        cfg = self.cfg
+        return AsyncIOEngine(store, worker_budget=cfg.io_worker_budget,
+                             chaos=cfg.chaos, retry=cfg.retry_policy(),
+                             sched=cfg.io_sched,
+                             class_weights=cfg.io_class_weights,
+                             qwait_high_s=cfg.io_qwait_high_s,
+                             qwait_low_s=cfg.io_qwait_low_s)
+
     # -----------------------------------------------------------------
     def _operators(self):
         cfg = self.cfg
-        env = DEFAULT_ENVELOPE
 
         def op_sample(ctx):
             ctx["mb"] = self.sampler.sample(ctx["seeds"])
@@ -330,7 +321,7 @@ class OutOfCoreGNNTrainer:
         def op_cache_refresh(ctx):
             # asynchronous tier migration on the io resource: placement
             # updates hide under the device's batch_build/train work
-            ctx["refresh"] = self.cache.maybe_refresh()
+            self.cache.maybe_refresh()
 
         def op_prefetch(ctx):
             # policy-driven prefetch on the io resource, double-buffered:
@@ -343,7 +334,7 @@ class OutOfCoreGNNTrainer:
                     self._pf_pending,
                     self.cache.maybe_prefetch(cfg.prefetch_rows, wait=False))
             if prev is not None:
-                ctx["prefetch"] = self.cache.complete_prefetch(prev)
+                self.cache.complete_prefetch(prev)
 
         def op_batch_build(ctx):
             # the feature array holds the batch's row bucket (its real rows,
@@ -409,159 +400,58 @@ class OutOfCoreGNNTrainer:
                                              wait=False)
             with self._pf_lock:
                 prev, self._wb_pending = self._wb_pending, pw
-                ctx["writeback"] = pw.result
-                # snapshot NOW: the next batch may complete this ticket
-                # (mutating result.virtual_s) once the swap is visible
-                ctx["wb_submit_virt"] = pw.result.virtual_s
             if prev is not None:
-                # incremental virt only: the submit-side charge (the RMW
-                # read) was billed to the batch that issued it
-                before = prev.result.virtual_s
-                ctx["wb_prev_virt"] = (self.cache.complete_write(prev)
-                                       .virtual_s - before)
+                self.cache.complete_write(prev)
             if cfg.embedding_flush_every > 0:
                 with self._pf_lock:
                     self._wb_batches += 1
                     due = self._wb_batches % cfg.embedding_flush_every == 0
                 if due:
-                    # harvest the just-submitted ticket HERE so its virt is
-                    # charged to this operator — the barrier would complete
-                    # it anyway, but then its storage seconds would vanish
-                    # from the pipeline cost model (FlushResult only carries
-                    # the barrier ticket)
+                    # land the just-submitted ticket before the barrier
                     with self._pf_lock:
                         cur, self._wb_pending = self._wb_pending, None
                     if cur is not None:
-                        before = cur.result.virtual_s
-                        ctx["wb_prev_virt"] = (
-                            ctx.get("wb_prev_virt", 0.0)
-                            + self.cache.complete_write(cur).virtual_s
-                            - before)
-                    ctx["wb_flush"] = self.cache.flush()
+                        self.cache.complete_write(cur)
+                    self.cache.flush()
                     if self.mom_cache is not None:
                         # the optimizer-state tables honor the same
                         # barrier: velocity rows are restart state too
-                        ctx["wb_mom_flush"] = self.mom_cache.flush()
+                        self.mom_cache.flush()
                     if self.adam_cache is not None:
-                        ctx["wb_adam_flush"] = self.adam_cache.flush()
-
-        # virtual costs under the paper envelope
-        rb = self.store.row_bytes
-
-        cpu_managed = cfg.mode == "cpu"
-
-        def vc_sample(ctx):
-            edges = sum(len(b.src_pos) for b in ctx["mb"].blocks)
-            # CPU-managed systems sample AND build the feature mini-batch on
-            # the CPU (paper I1: 70-98% of epoch time); device-managed
-            # sampling is ~50x faster (massively parallel)
-            rate = SAMPLE_RATE_CPU if cpu_managed else SAMPLE_RATE_DEVICE
-            return edges * 16 / rate
-
-        def vc_submit(ctx):
-            # decoupled submission only BUILDS per-shard SQE batches — the
-            # storage service time is charged where the ticket resolves
-            # (vc_complete), with the virtual seconds the engine actually
-            # accounted for the striped/coalesced read
-            tk = ctx["pending"].ticket
-            return 2e-6 * (tk.shards if tk is not None else 0)
-
-        def vc_complete(ctx):
-            # storage and remote legs resolve on parallel engine queues —
-            # the operator costs the slower of the two (io_virt), which
-            # collapses to storage_virt in single-node mode
-            return ctx["pending"].io_virt
-
-        def vc_lookup(ctx):
-            pg = ctx["pending"]
-            t_host = pg.n_host * rb / env.dram_bw + pcie_time(pg.n_host * rb)
-            t_dev = pg.n_device * rb / env.hbm_bw
-            return t_host + t_dev
-
-        def vc_refresh(ctx):
-            r = ctx.get("refresh")
-            return r.virtual_s if r is not None else 0.0
-
-        def vc_prefetch(ctx):
-            r = ctx.get("prefetch")
-            return r.virtual_s if r is not None else 0.0
-
-        def vc_writeback(ctx):
-            r = ctx.get("writeback")
-            if r is None:
-                return 0.0
-            # tier writes move bytes over HBM/DRAM; this batch's RMW read
-            # rides r.virtual_s at submit time, while the storage WRITE
-            # ticket is charged one batch later, when the operator that
-            # completes it harvests the virtual seconds it resolved with
-            # (wb_prev_virt) — the split-phase cadence in the cost model
-            virt = (r.device_rows * rb / env.hbm_bw
-                    + r.host_rows * rb / env.dram_bw
-                    + ctx.get("wb_submit_virt", 0.0)
-                    + ctx.get("wb_prev_virt", 0.0))
-            fl = ctx.get("wb_flush")
-            mfl = ctx.get("wb_mom_flush")
-            afl = ctx.get("wb_adam_flush")
-            return (virt + (fl.virtual_s if fl is not None else 0.0)
-                    + (mfl.virtual_s if mfl is not None else 0.0)
-                    + (afl.virtual_s if afl is not None else 0.0))
-
-        def vc_h2d(ctx):
-            # device-managed paths (Helios/GIDS) land storage + host rows in
-            # device memory directly (GPU-initiated DMA / UVA), so batch
-            # assembly moves only index tensors; CPU-managed systems gather
-            # the whole mini-batch into a staging buffer on the CPU and DMA
-            # it across PCIe once more (paper I2, Fig. 1(b))
-            n_real = ctx["mb"].n_real
-            if cpu_managed:
-                nbytes = n_real * rb
-                return nbytes / HOST_STAGE_BW + pcie_time(nbytes)
-            edges = sum(len(b.src_pos) for b in ctx["mb"].blocks)
-            return pcie_time(edges * 8 + n_real * 8)
-
-        def vc_train(ctx):
-            edges = sum(int(m.sum()) for m in ctx["tensors"][2])
-            flops = 4 * edges * self.store.row_dim * self.cfg.hidden
-            return flops / MATMUL_RATE
+                        self.adam_cache.flush()
 
         plan = [
-            Operator("sample", op_sample, "host", (), vc_sample),
-            Operator("io_submit", op_io_submit, "io", ("sample",), vc_submit),
-            Operator("cache_lookup", op_cache_lookup, "host", ("io_submit",),
-                     vc_lookup),
-            Operator("io_complete", op_io_complete, "io", ("io_submit",),
-                     vc_complete),
+            Operator("sample", op_sample, "host"),
+            Operator("io_submit", op_io_submit, "io", ("sample",)),
+            Operator("cache_lookup", op_cache_lookup, "host", ("io_submit",)),
+            Operator("io_complete", op_io_complete, "io", ("io_submit",)),
             Operator("cache_refresh", op_cache_refresh, "io",
-                     ("io_complete",), vc_refresh),
+                     ("io_complete",)),
             Operator("batch_build", op_batch_build, "device",
-                     ("cache_lookup", "io_complete"), vc_h2d),
-            Operator("train", op_train, "device", ("batch_build",), vc_train),
+                     ("cache_lookup", "io_complete")),
+            Operator("train", op_train, "device", ("batch_build",)),
         ]
         if cfg.prefetch_rows > 0:
             plan.insert(5, Operator("prefetch", op_prefetch, "io",
-                                    ("io_complete",), vc_prefetch))
+                                    ("io_complete",)))
         if cfg.train_embeddings:
             plan.append(Operator("embedding_writeback",
-                                 op_embedding_writeback, "io", ("train",),
-                                 vc_writeback))
+                                 op_embedding_writeback, "io", ("train",)))
         return plan
 
     # -----------------------------------------------------------------
     def train(self, n_batches: int) -> dict:
         cfg = self.cfg
-        mode = {"helios": "deep", "helios-nopipe": "nopipe",
-                "helios-nocache": "deep", "gids": "nopipe",
-                "cpu": "cpu"}[cfg.mode]
-        pipe = PipelineExecutor(self._operators(), mode=mode,
+        pipe = PipelineExecutor(self._operators(),
                                 prefetch_depth=cfg.prefetch_depth)
 
         def make_ctx(i):
             # bounded-cost unique draw: O(batch) expected, not O(n_vertices).
             # The rng is derived from the BATCH INDEX, not a shared stream:
-            # deep-pipeline mode calls make_ctx from concurrent pipe-batch
+            # the pipeline calls make_ctx from concurrent pipe-batch
             # threads, and a shared Generator is neither thread-safe nor
             # deterministic under interleaving — per-index derivation makes
-            # the seed stream reproducible in every pipeline mode
+            # the seed stream reproducible at every prefetch depth
             rng = np.random.default_rng([cfg.seed, 0x5EED, i])
             seeds = draw_unique(rng, self.g.n_vertices, cfg.batch_size)
             return {"seeds": seeds, "batch": i}
@@ -624,17 +514,7 @@ class OutOfCoreGNNTrainer:
                      "throttle_engaged": io_snap.throttle_engaged,
                      "throttle_released": io_snap.throttle_released,
                      "throttled_skipped_rows":
-                         cs_snap.throttled_skipped_rows,
-                     # pipeline-bubble attribution (always on; see
-                     # repro.obs.analyze.overlap_report)
-                     "overlap_efficiency":
-                         out["overlap"]["overlap_efficiency"],
-                     "bubble_frac": out["overlap"]["bubble_frac"]}
-        tr = _trace.TRACER
-        if tr is not None and tr.enabled:
-            # the traced span tree yields the full per-phase attribution
-            out["obs"] = _analyze.analyze_epoch(tr,
-                                                makespan=out["virtual_s"])
+                         cs_snap.throttled_skipped_rows}
         if cfg.train_embeddings:
             cs = cs_snap
             out["writeback"] = {

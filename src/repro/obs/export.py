@@ -4,13 +4,13 @@ Layout
 ------
 Two synthetic processes, one per timebase:
 
-* pid 1 ``virtual`` — spans that carry virtual-clock stamps (pipeline
-  ops, IO tickets, serve phases).  ``ts``/``dur`` are virtual
-  microseconds, so the Perfetto timeline *is* the simulated schedule:
-  one track per shard worker (``ssd0``..), per pipeline stage resource
-  (``host``/``io``/``device``), per peer, per serve phase.
-* pid 2 ``wall`` — spans without virtual stamps (queue waits, reaps,
-  host-side bookkeeping), on real wall-clock microseconds.
+* pid 1 ``virtual`` — spans that carry virtual-clock stamps (the
+  serving loop's phases).  ``ts``/``dur`` are virtual microseconds, one
+  track per serve phase.
+* pid 2 ``wall`` — every other span (pipeline operators, IO, cache,
+  queue waits), on the host's wall-clock microseconds: one track per
+  pipeline resource (``host``/``io``/``device``), per shard worker
+  (``ssd0``..), per peer.
 
 Span args carry ``sid``/``parent`` so the nesting tree survives the
 flat event list; instant events (retries, hedges, reroutes) become

@@ -1,4 +1,4 @@
-"""Virtual-time tracer: nested spans stamped with wall AND virtual time.
+"""Tracer: nested spans stamped with wall time (and serving's virtual time).
 
 Design constraints (see ISSUE 9):
 
@@ -12,12 +12,10 @@ Design constraints (see ISSUE 9):
 
   so the disabled cost is one global load and an ``is None`` test.
 
-* **Two timebases.**  The system runs on a :class:`VirtualClock`
-  (simulated SSD/PCIe/NVLink seconds) while threads burn real wall
-  time.  Spans carry both: ``t0``/``t1`` are wall seconds relative to
-  the tracer epoch, ``v0``/``v1`` are virtual seconds when the layer
-  knows them (pipeline ops, IO tickets, serve phases) and ``None``
-  for pure host work (queue waits, reaps).
+* **Wall time first.**  ``t0``/``t1`` are the host's wall seconds
+  relative to the tracer epoch, on every span.  ``v0``/``v1`` are the
+  serving loop's virtual-clock interval on its phase spans and ``None``
+  everywhere else (training, IO, cache).
 
 * **Thread-safe, allocation-light.**  Spans are ``__slots__`` records
   appended to a plain list (``list.append`` is atomic under the GIL);

@@ -96,23 +96,62 @@ def test_cache_skew_hit_rate(store):
     assert cache.stats.hit_rate > 0.5
 
 
+def _sleeping_ops(secs):
+    """A three-stage chain, one stage per resource, each sleeping ``secs``."""
+    def nap(ctx):
+        time.sleep(secs)
+    return [Operator("a", nap, "host"),
+            Operator("b", nap, "io", ("a",)),
+            Operator("c", nap, "device", ("b",))]
+
+
 def test_pipeline_overlap_beats_serial():
-    """Deep pipeline virtual time < serial when stages use distinct
-    resources (paper Fig. 11)."""
-    def mk_ops():
-        return [
-            Operator("a", lambda ctx: None, "host", (), lambda c: 0.010),
-            Operator("b", lambda ctx: None, "io", ("a",), lambda c: 0.010),
-            Operator("c", lambda ctx: None, "device", ("b",), lambda c: 0.010),
-        ]
-    deep = PipelineExecutor(mk_ops(), mode="deep", prefetch_depth=3)
-    out_d = deep.run(lambda i: {}, 12)
-    deep.close()
-    ser = PipelineExecutor(mk_ops(), mode="nopipe")
-    out_s = ser.run(lambda i: {}, 12)
-    ser.close()
-    # serial: 12*30ms; deep: pipeline fills -> ~12*10ms + 20ms
-    assert out_d["virtual_s"] < 0.75 * out_s["virtual_s"]
+    """Batches in flight together overlap their stages on the host's wall
+    clock (paper Fig. 11): depth 3 against one batch at a time."""
+    walls = {}
+    for depth in (1, 3):
+        pipe = PipelineExecutor(_sleeping_ops(0.015), prefetch_depth=depth)
+        walls[depth] = pipe.run(lambda i: {}, 10)["wall_s"]
+        pipe.close()
+    # one at a time: 10 x 45 ms; depth 3 fills the stages: ~10 x 15 + 30 ms
+    assert walls[1] >= 10 * 0.045
+    assert walls[3] < 0.75 * walls[1]
+
+
+def test_pipeline_depth_one_runs_one_batch_at_a_time():
+    """prefetch_depth=1: batch i+1's first operator starts after batch i's
+    last one ended, while a batch's independent operators still run."""
+    from repro.obs import trace as obs_trace
+
+    def nap(ctx):
+        time.sleep(0.002)
+    ops = [Operator("s", nap, "host"),
+           Operator("x", nap, "io", ("s",)),
+           Operator("y", nap, "host", ("s",)),
+           Operator("t", nap, "device", ("x", "y"))]
+    prev = obs_trace.TRACER
+    tr = obs_trace.install()
+    try:
+        pipe = PipelineExecutor(ops, prefetch_depth=1)
+        out = pipe.run(lambda i: {}, 6)
+        pipe.close()
+    finally:
+        obs_trace.TRACER = prev
+    assert {k: v["calls"] for k, v in out["stages"].items()} == dict.fromkeys(
+        "sxyt", 6)
+    spans = [s for s in tr.spans if s.cat == "pipe"]
+    by_batch = {}
+    for s in spans:
+        by_batch.setdefault(s.args["batch"], []).append(s)
+    assert sorted(by_batch) == list(range(6))
+    for i in range(5):
+        assert (min(s.t0 for s in by_batch[i + 1])
+                >= max(s.t1 for s in by_batch[i]))
+
+
+def test_pipeline_rejects_depth_below_one():
+    with pytest.raises(ValueError):
+        PipelineExecutor(_sleeping_ops(0.0), prefetch_depth=0)
 
 
 def test_pipeline_dependency_order():
@@ -122,7 +161,7 @@ def test_pipeline_dependency_order():
         Operator("y", lambda ctx: seen.append("y"), "io", ("x",)),
         Operator("z", lambda ctx: seen.append("z"), "device", ("y",)),
     ]
-    pipe = PipelineExecutor(ops, mode="deep", prefetch_depth=1)
+    pipe = PipelineExecutor(ops, prefetch_depth=1)
     pipe.run(lambda i: {}, 1)
     pipe.close()
     assert seen == ["x", "y", "z"]
@@ -204,28 +243,6 @@ def test_presample_draws_unique_seeds():
     for seeds in spy.seen:
         assert len(np.unique(seeds)) == len(seeds)  # without replacement
         assert len(seeds) == 64
-
-
-def test_pipeline_ablation_mode_ordering():
-    """On a fixed operator plan, virtual time orders deep < nopipe <= cpu
-    (the trainer's ablation axes, paper Figs. 5/11)."""
-    def mk_ops(host_cost):
-        return [
-            Operator("prep", lambda ctx: None, "host", (),
-                     lambda c: host_cost),
-            Operator("io", lambda ctx: None, "io", ("prep",),
-                     lambda c: 0.010),
-            Operator("train", lambda ctx: None, "device", ("io",),
-                     lambda c: 0.008),
-        ]
-    times = {}
-    for mode, host_cost in (("deep", 0.005), ("nopipe", 0.005),
-                            ("cpu", 0.020)):
-        pipe = PipelineExecutor(mk_ops(host_cost), mode=mode,
-                                prefetch_depth=3)
-        times[mode] = pipe.run(lambda i: {}, 8)["virtual_s"]
-        pipe.close()
-    assert times["deep"] < times["nopipe"] <= times["cpu"]
 
 
 def test_cache_stats_zero_batch_hit_rate():

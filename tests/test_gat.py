@@ -184,7 +184,7 @@ def _store(tmp_path):
 
 def test_trainer_runs_gat_and_counts_its_edges(graph, tmp_path):
     cfg = TrainerConfig(model="gat", hidden=HIDDEN, batch_size=BATCH,
-                        fanouts=FANOUTS, mode="helios-nopipe",
+                        fanouts=FANOUTS, prefetch_depth=1,
                         presample_batches=2)
     trn = OutOfCoreGNNTrainer(graph, _store(tmp_path), cfg)
     with trn:
@@ -214,7 +214,7 @@ def test_trainer_runs_gat_and_counts_its_edges(graph, tmp_path):
 
 def test_sage_edge_counters_are_the_blocks(graph, tmp_path):
     cfg = TrainerConfig(model="sage", hidden=HIDDEN, batch_size=BATCH,
-                        fanouts=FANOUTS, mode="helios-nopipe",
+                        fanouts=FANOUTS, prefetch_depth=1,
                         presample_batches=2)
     with OutOfCoreGNNTrainer(graph, _store(tmp_path), cfg) as trn:
         drawn = []

@@ -64,18 +64,32 @@ def test_gnn_loss_grad(model, graph):
     assert gn > 0
 
 
-@pytest.mark.parametrize("mode", ["helios", "helios-nopipe", "gids", "cpu"])
-def test_out_of_core_training_improves(tmp_path, mode, graph):
+@pytest.mark.parametrize("model,depth", [("sage", 2), ("gcn", 2), ("gat", 2),
+                                         ("sage", 1)],
+                         ids=["sage", "gcn", "gat", "sage-depth1"])
+def test_out_of_core_training_improves(tmp_path, model, depth, graph):
     store = FeatureStore(str(tmp_path / "f"), n_rows=5000, row_dim=32,
                          n_shards=4, create=True, rng_seed=3)
+    # 20 steps at lr 3e-3: GCN's and GAT's loss needs about that long to
+    # move past the batch-to-batch noise at this size (GraphSAGE's moves
+    # in 10 at the default lr)
     with OutOfCoreGNNTrainer(graph, store, TrainerConfig(
-            mode=mode, batch_size=64, fanouts=(4, 3), hidden=32,
-            presample_batches=2)) as tr:
-        out = tr.train(10)
+            model=model, prefetch_depth=depth, batch_size=64,
+            fanouts=(4, 3), hidden=32, presample_batches=2,
+            lr=3e-3)) as tr:
+        out = tr.train(20)
         # trend over windows, not endpoints: single-step loss is noisy at
         # this scale, the first/last-3 means decrease reliably
         losses = [m["loss"] for m in tr.metrics_log]
         assert np.mean(losses[-3:]) < np.mean(losses[:3])
         assert out["cache"]["storage_misses"] >= 0
-        if mode == "helios":
-            assert out["cache"]["hit_rate"] > 0
+        assert out["cache"]["hit_rate"] > 0
+        assert out["stages"]["train"]["calls"] == 20
+
+
+@pytest.mark.parametrize("mode", ["helios-nopipe", "helios-nocache", "gids",
+                                  "cpu"])
+def test_trainer_rejects_removed_modes(mode):
+    """The trainer runs one system; the ablation modes are gone."""
+    with pytest.raises(ValueError, match="helios"):
+        TrainerConfig(mode=mode)

@@ -34,7 +34,7 @@ def _trainer(graph, tmp_path, **kw):
     st = FeatureStore(str(tmp_path / "f"), n_rows=N_VERTICES, row_dim=ROW_DIM,
                       n_shards=4, create=True, rng_seed=3,
                       writable=kw.get("train_embeddings", False))
-    cfg = dict(mode="helios-nopipe", batch_size=64, fanouts=(4, 3), hidden=32,
+    cfg = dict(prefetch_depth=1, batch_size=64, fanouts=(4, 3), hidden=32,
                presample_batches=2)
     cfg.update(kw)
     return OutOfCoreGNNTrainer(graph, st, TrainerConfig(**cfg)), st
@@ -42,7 +42,8 @@ def _trainer(graph, tmp_path, **kw):
 
 def _record(trn):
     """Keep each batch the sampler draws and what the step is fed, in
-    order (the serial pipeline samples batch i before it trains it)."""
+    order (at prefetch depth 1 batch i is sampled and trained before
+    batch i+1 is drawn)."""
     mbs, fed = [], []
     sample, step = trn.sampler.sample, trn.step_fn
 
@@ -114,7 +115,7 @@ def test_step_is_fed_the_real_rows_then_zeros(graph, tmp_path):
 
 
 def test_bucket_never_shrinks_and_compiles_once_a_rung(graph, tmp_path):
-    trn, _ = _trainer(graph, tmp_path, mode="helios")
+    trn, _ = _trainer(graph, tmp_path, prefetch_depth=2)
     with trn:
         jitted = trn.step_fn
         mbs, fed = _record(trn)

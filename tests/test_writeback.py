@@ -733,7 +733,7 @@ def test_trainer_embedding_writeback(tmp_path):
     store = FeatureStore(str(tmp_path / "f"), n_rows=800, row_dim=8,
                          n_shards=3, create=True, rng_seed=1, writable=True)
     before = store.read_rows(np.arange(800)).copy()
-    cfg = TrainerConfig(mode="helios-nopipe", batch_size=32, fanouts=(3, 2),
+    cfg = TrainerConfig(prefetch_depth=1, batch_size=32, fanouts=(3, 2),
                         hidden=8, presample_batches=2, train_embeddings=True,
                         embedding_lr=0.5, embedding_flush_every=2)
     with OutOfCoreGNNTrainer(g, store, cfg) as tr:
@@ -757,7 +757,7 @@ def test_trainer_adam_table_rides_flush_barriers(tmp_path):
     g = synth_graph(800, 6, skew=1.0, seed=0)
     store = FeatureStore(str(tmp_path / "f"), n_rows=800, row_dim=8,
                          n_shards=3, create=True, rng_seed=1, writable=True)
-    cfg = TrainerConfig(mode="helios-nopipe", batch_size=32, fanouts=(3, 2),
+    cfg = TrainerConfig(prefetch_depth=1, batch_size=32, fanouts=(3, 2),
                         hidden=8, presample_batches=2, train_embeddings=True,
                         embedding_lr=0.5, embedding_flush_every=2,
                         embedding_momentum=0.9, embedding_adam=0.99)
